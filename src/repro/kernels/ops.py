@@ -67,16 +67,17 @@ def decode_attention(
 @functools.partial(jax.jit, static_argnames=("scale",))
 def paged_decode_attention(
     q: jax.Array,  # (B, 1, H, D) — model layout
-    pool_k: jax.Array,  # (num_pages, page_size, KV, D)
+    pool_k: jax.Array,  # (L, num_pages, page_size, KV, D)
     pool_v: jax.Array,
     page_tables: jax.Array,  # (B, max_pages) int32
     lengths: jax.Array,  # (B,) int32 — valid tokens per request
+    layer: jax.Array,  # int32 scalar — the layer of the stacked pools to read
     scale: Optional[float] = None,
 ) -> jax.Array:
     from repro.kernels import paged_attention as _paged
 
     out = _paged.paged_decode_attention(
-        q[:, 0], pool_k, pool_v, page_tables, lengths,
+        q[:, 0], pool_k, pool_v, page_tables, lengths, layer,
         scale=scale, interpret=_interpret(),
     )
     return out[:, None]

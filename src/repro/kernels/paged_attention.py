@@ -8,7 +8,8 @@ can pick the right page out of the pool — the TPU analogue of a GPU kernel
 chasing the page table through shared memory.
 
 Layouts:
-  pool_k / pool_v : (num_pages, page_size, KV, D)
+  pool_k / pool_v : (L, num_pages, page_size, KV, D) — every layer's pool
+  layer           : int32 scalar — the layer this call reads
   page_tables     : (B, max_pages) int32 — page ids per request, row-major
   lengths         : (B,) int32 — valid tokens per request
   q               : (B, H, D)
@@ -33,7 +34,9 @@ NEG_INF = -1e30
 
 
 def _paged_kernel(
-    scalars_ref,  # (B, max_pages+1) int32: [page ids..., length]
+    tables_ref,  # (B, max_pages) int32: page ids (read by the index maps)
+    lengths_ref,  # (B,) int32
+    layer_ref,  # (1,) int32: layer of the stacked pool
     q_ref, k_ref, v_ref,
     o_ref,
     m_ref, l_ref, acc_ref,
@@ -49,15 +52,16 @@ def _paged_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = scalars_ref[b, -1]
+    # a scalar ref takes no negative index on the TPU (-1 reads out of row)
+    length = lengths_ref[b]
     page_start = j * page_size
     live = page_start < length
 
     @pl.when(live)
     def _compute():
         q = q_ref[0].astype(jnp.float32)  # (H, D)
-        k = k_ref[0].astype(jnp.float32)  # (page_size, KV, D)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)  # (page_size, KV, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         H, D = q.shape
         P, KV, _ = k.shape
         qg = q.reshape(KV, groups, D)
@@ -88,40 +92,39 @@ def _paged_kernel(
 
 def paged_decode_attention(
     q: jax.Array,  # (B, H, D)
-    pool_k: jax.Array,  # (num_pages, page_size, KV, D)
+    pool_k: jax.Array,  # (L, num_pages, page_size, KV, D)
     pool_v: jax.Array,
     page_tables: jax.Array,  # (B, max_pages) int32
     lengths: jax.Array,  # (B,) int32
+    layer: jax.Array,  # int32 scalar
     *,
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> jax.Array:
     B, H, D = q.shape
-    num_pages, page_size, KV, _ = pool_k.shape
+    page_size, KV = pool_k.shape[2], pool_k.shape[3]
     max_pages = page_tables.shape[1]
     G = H // KV
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
-    scalars = jnp.concatenate(
-        [page_tables.astype(jnp.int32), lengths.astype(jnp.int32)[:, None]], axis=1
-    )  # (B, max_pages+1)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_map(b, j, scalars):
+    def q_map(b, j, tables, lens, layer_ref):
         return (b, 0, 0)
 
-    def kv_map(b, j, scalars):
-        return (scalars[b, j], 0, 0, 0)
+    def kv_map(b, j, tables, lens, layer_ref):
+        return (layer_ref[0], tables[b, j], 0, 0, 0)
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, groups=G, page_size=page_size
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(B, max_pages),
         in_specs=[
             pl.BlockSpec((1, H, D), q_map),
-            pl.BlockSpec((1, page_size, KV, D), kv_map),
-            pl.BlockSpec((1, page_size, KV, D), kv_map),
+            pl.BlockSpec((1, 1, page_size, KV, D), kv_map),
+            pl.BlockSpec((1, 1, page_size, KV, D), kv_map),
         ],
         out_specs=pl.BlockSpec((1, H, D), q_map),
         scratch_shapes=[
@@ -135,4 +138,5 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
-    )(scalars, q, pool_k, pool_v)
+    )(page_tables.astype(jnp.int32), lengths.astype(jnp.int32), layer,
+      q, pool_k, pool_v)

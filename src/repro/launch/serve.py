@@ -10,13 +10,20 @@ factor is printed.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --smoke \
       --requests 16 --batch 4 --new-tokens 8
+
+Without ``--smoke`` the model runs at its full published width.  On a TPU
+the attention runs in the Pallas kernels; elsewhere on the jnp path.
+``chip_smoke.py`` at the repository root drives the same
+:func:`build_engine` / :func:`make_requests` steps on one chip.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import time
+import os
+from pathlib import Path
+from typing import List, Sequence
 
 import jax
 import numpy as np
@@ -25,13 +32,77 @@ from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core.arch_bridge import tpu_arch_profiles
 from repro.core.online_profiles import MeasuredProfile
 from repro.models import Model
+from repro.models.config import ModelConfig
 from repro.serving import Engine, Request, run_closed_loop
+
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is unset:
+# one fixed directory inside the checkout, so every run finds what earlier
+# runs compiled (the path is part of the cache key; a moving one never hits).
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+    Call it before anything compiles.  ``JAX_COMPILATION_CACHE_DIR``, when
+    set, is used as it is (JAX reads it itself, so nothing is set here);
+    otherwise the cache lives at :data:`COMPILE_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
+def build_engine(
+    cfg: ModelConfig,
+    *,
+    use_kernels: bool,
+    batch: int,
+    max_len: int,
+    kv_backend: str = "auto",
+    page_size: int = 16,
+    temperature: float = 0.0,
+    top_k: int = 0,
+    seed: int = 0,
+) -> Engine:
+    """The model with seeded random weights, wrapped in a serving engine."""
+    model = Model(cfg, remat=False, use_kernels=use_kernels)
+    params, _ = model.init(jax.random.PRNGKey(seed))
+    return Engine(
+        model, params, batch=batch, max_len=max_len,
+        kv_backend=kv_backend, page_size=page_size,
+        temperature=temperature, top_k=top_k,
+    )
+
+
+def make_requests(
+    cfg: ModelConfig,
+    n: int,
+    prompt_lens: Sequence[int],
+    new_tokens: int,
+    seed: int = 0,
+) -> List[Request]:
+    """``n`` seeded requests of random prompt tokens; request ``i`` has a
+    prompt of ``prompt_lens[i % len(prompt_lens)]`` tokens."""
+    rng = np.random.default_rng(seed)
+    return [
+        Request(
+            rid=i,
+            prompt=rng.integers(
+                1, cfg.vocab_size, size=prompt_lens[i % len(prompt_lens)]
+            ).astype(np.int32),
+            max_new_tokens=new_tokens,
+        )
+        for i in range(n)
+    ]
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen3-8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="the architecture's reduced smoke config instead of "
+                         "its full published width")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -49,25 +120,18 @@ def main() -> None:
                          "metrics schema as the simulator's obs block "
                          "(docs/OBSERVABILITY.md)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    model = Model(cfg, remat=False)
-    params, _ = model.init(jax.random.PRNGKey(args.seed))
-    engine = Engine(
-        model, params, batch=args.batch, max_len=args.max_len,
-        kv_backend=args.backend, page_size=args.page_size,
-        temperature=args.temperature, top_k=args.top_k,
+    engine = build_engine(
+        cfg, use_kernels=jax.devices()[0].platform == "tpu",
+        batch=args.batch, max_len=args.max_len, kv_backend=args.backend,
+        page_size=args.page_size, temperature=args.temperature,
+        top_k=args.top_k, seed=args.seed,
     )
-
-    rng = np.random.default_rng(args.seed)
-    reqs = [
-        Request(
-            rid=i,
-            prompt=rng.integers(1, cfg.vocab_size, size=args.prompt_len).astype(np.int32),
-            max_new_tokens=args.new_tokens,
-        )
-        for i in range(args.requests)
-    ]
+    reqs = make_requests(
+        cfg, args.requests, [args.prompt_len], args.new_tokens, args.seed
+    )
     measured = MeasuredProfile(tpu_arch_profiles([args.arch]))
     stats = run_closed_loop(
         engine, reqs, seed=args.seed,

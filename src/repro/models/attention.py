@@ -240,34 +240,47 @@ def gqa_decode_paged(
     pos: jax.Array,  # (B,) int32 per-slot position of the new token
     live: jax.Array,  # (B,) bool
     use_kernels: bool = False,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Ragged decode against the paged pool: the new token's k/v is scattered
     into its slot's current page (idle slots are routed to an out-of-bounds
     page id, so jax drops their write), then attention runs over the pages —
     the Pallas paged kernel when ``use_kernels``, a gather + flat-decode
-    reference otherwise."""
+    reference otherwise.
+
+    With ``layer`` (an int32 scalar), the pools are the whole layer stack
+    ``(L, P, ps, KV, hd)`` and only that layer's pages are written and read:
+    a layer scan carries the stacked pools and updates them in place rather
+    than slicing out and re-stacking a copy per layer."""
     B = x.shape[0]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     cpos, _ = normalize_pos(pos, B)
     q, k_new, v_new = _gqa_qkv(p, cfg, x, cpos[:, None])
     pool_k, pool_v = cache["pool_k"], cache["pool_v"]
-    num_pages, ps = pool_k.shape[0], pool_k.shape[1]
+    stacked = layer is not None
+    if not stacked:
+        pool_k, pool_v, layer = pool_k[None], pool_v[None], jnp.int32(0)
+    num_pages, ps = pool_k.shape[1], pool_k.shape[2]
     page = page_tables[jnp.arange(B), cpos // ps]
     page = jnp.where(live, page, num_pages)  # OOB => scatter dropped
     off = cpos % ps
-    pool_k = pool_k.at[page, off].set(k_new[:, 0], mode="drop")
-    pool_v = pool_v.at[page, off].set(v_new[:, 0], mode="drop")
+    pool_k = pool_k.at[layer, page, off].set(k_new[:, 0], mode="drop")
+    pool_v = pool_v.at[layer, page, off].set(v_new[:, 0], mode="drop")
     lengths = jnp.where(live, cpos + 1, 0)
     if use_kernels:
         from repro.kernels import ops  # lazy: kernels are optional at import
 
-        o = ops.paged_decode_attention(q, pool_k, pool_v, page_tables, lengths)
+        o = ops.paged_decode_attention(
+            q, pool_k, pool_v, page_tables, lengths, layer
+        )
     else:
         S = page_tables.shape[1] * ps
-        k = pool_k[page_tables].reshape(B, S, KV, hd)
-        v = pool_v[page_tables].reshape(B, S, KV, hd)
+        k = pool_k[layer, page_tables].reshape(B, S, KV, hd)
+        v = pool_v[layer, page_tables].reshape(B, S, KV, hd)
         valid = jnp.arange(S)[None, :] < lengths[:, None]
         o = kernels_bridge.decode_attention(q, k, v, valid)
+    if not stacked:
+        pool_k, pool_v = pool_k[0], pool_v[0]
     new_cache = {"pool_k": pool_k, "pool_v": pool_v}
     return o.reshape(B, 1, H * hd) @ p["wo"], new_cache
 

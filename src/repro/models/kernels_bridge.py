@@ -20,6 +20,11 @@ import jax
 import jax.numpy as jnp
 
 
+# Sequence tile of the flash prefill kernel: a prefill whose length is a
+# multiple of it runs in the kernel, any other length on the jnp path.
+FLASH_TILE = 128
+
+
 def _grouped(q: jax.Array, kv_heads: int):
     B, S, H, hd = q.shape
     G = H // kv_heads
@@ -62,7 +67,7 @@ def causal_attention(
     """Causal (optionally sliding-window) attention, (B,S,H,hd) layout."""
     B, S, H, hd = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    if use_kernels and q.shape[-1] == v.shape[-1] and S % 128 == 0:
+    if use_kernels and q.shape[-1] == v.shape[-1] and S % FLASH_TILE == 0:
         # (MLA's q head dim != v head dim and non-tile-aligned S fall back
         # to the jnp path; the kernel covers the GQA serving hot path)
         from repro.kernels import ops  # lazy: kernels are optional at import

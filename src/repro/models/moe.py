@@ -121,19 +121,7 @@ def moe_forward_shard_map(
     outputs over the expert axis — the same all-reduce a dense TP MLP pays.
     Dispatch itself moves **zero** bytes.
     """
-    import inspect
-
-    try:
-        from jax import shard_map  # newer jax re-exports at top level
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-
-    # replication checking kwarg was renamed check_rep -> check_vma
-    if "check_vma" in inspect.signature(shard_map).parameters:
-        no_rep_check = {"check_vma": False}
-    else:
-        no_rep_check = {"check_rep": False}
 
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
@@ -197,7 +185,7 @@ def moe_forward_shard_map(
         if shared is not None
         else None
     )
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -209,6 +197,6 @@ def moe_forward_shard_map(
             shared_specs,
         ),
         out_specs=(P(dp, None, None), P()),
-        **no_rep_check,
+        check_vma=False,
     )(x, p["router"], p["we_gate"], p["we_up"], p["we_down"], shared)
     return out, aux

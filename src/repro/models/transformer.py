@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +66,13 @@ def _attn_cache_specs(cfg, dp, seq_axis):
     if cfg.attention_kind == "mla":
         return attn.mla_cache_specs(cfg, dp, seq_axis)
     return attn.gqa_cache_specs(cfg, dp, seq_axis)
+
+
+def _stacked(n: int, layer_cache: Params) -> Params:
+    """``n`` copies of one layer's cache along a new leading scan axis, each
+    leaf allocated once at its stacked size — stacking ``n`` per-layer
+    copies would hold the whole cache twice while it is built."""
+    return jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape), layer_cache)
 
 
 @dataclasses.dataclass
@@ -269,28 +276,25 @@ class Model:
         cfg = self.cfg
         dtype = DTYPES[cfg.dtype]
 
-        def stack(n, make):
-            return jax.tree.map(lambda *xs: jnp.stack(xs), *[make() for _ in range(n)])
-
         if cfg.arch_type in ("dense", "vlm", "audio"):
             return {
-                "layers": stack(
-                    cfg.num_layers, lambda: _attn_init_cache(cfg, batch, max_len, dtype)
+                "layers": _stacked(
+                    cfg.num_layers, _attn_init_cache(cfg, batch, max_len, dtype)
                 )
             }
         if cfg.arch_type == "moe":
             out: Params = {}
             for i in range(cfg.first_dense_layers):
                 out[f"dense_{i}"] = _attn_init_cache(cfg, batch, max_len, dtype)
-            out["layers"] = stack(
+            out["layers"] = _stacked(
                 cfg.num_layers - cfg.first_dense_layers,
-                lambda: _attn_init_cache(cfg, batch, max_len, dtype),
+                _attn_init_cache(cfg, batch, max_len, dtype),
             )
             return out
         if cfg.arch_type == "ssm":
             return {
-                "layers": stack(
-                    cfg.num_layers, lambda: ssm_mod.ssm_init_cache(cfg, batch, dtype)
+                "layers": _stacked(
+                    cfg.num_layers, ssm_mod.ssm_init_cache(cfg, batch, dtype)
                 )
             }
         if cfg.arch_type == "hybrid":
@@ -303,7 +307,7 @@ class Model:
                 return c
 
             return {
-                "layers": stack(cfg.num_layers // cfg.shared_attn_every, superblock)
+                "layers": _stacked(cfg.num_layers // cfg.shared_attn_every, superblock())
             }
         raise ValueError(cfg.arch_type)
 
@@ -554,16 +558,13 @@ class Model:
         def pools():
             return attn.gqa_init_paged_cache(cfg, num_pages, page_size, dtype)
 
-        def stack(n, make):
-            return jax.tree.map(lambda *xs: jnp.stack(xs), *[make() for _ in range(n)])
-
         out: Params = {"page_tables": jnp.zeros((batch, max_pages), jnp.int32)}
         if cfg.arch_type in ("dense", "vlm", "audio"):
-            out["layers"] = stack(cfg.num_layers, pools)
+            out["layers"] = _stacked(cfg.num_layers, pools())
         elif cfg.arch_type == "moe":
             for i in range(cfg.first_dense_layers):
                 out[f"dense_{i}"] = pools()
-            out["layers"] = stack(cfg.num_layers - cfg.first_dense_layers, pools)
+            out["layers"] = _stacked(cfg.num_layers - cfg.first_dense_layers, pools())
         elif cfg.arch_type == "hybrid":
             def superblock():
                 c = {
@@ -573,7 +574,7 @@ class Model:
                 c["attn"] = pools()
                 return c
 
-            out["layers"] = stack(cfg.num_layers // cfg.shared_attn_every, superblock)
+            out["layers"] = _stacked(cfg.num_layers // cfg.shared_attn_every, superblock())
         else:
             raise ValueError(cfg.arch_type)
         return out
@@ -583,7 +584,11 @@ class Model:
     ) -> Tuple[jax.Array, Params]:
         """Like :meth:`decode_step` but with attention KV in page pools
         (``cache`` from :meth:`init_paged_cache`).  Same ragged contract:
-        per-slot ``pos``, idle slots (``pos < 0``) never touch any cache."""
+        per-slot ``pos``, idle slots (``pos < 0``) never touch any cache.
+
+        The layer scan carries the stacked pools and writes each layer's new
+        k/v into them in place, so a donated cache is the step's only copy
+        of the pool."""
         cfg = self.cfg
         B = token.shape[0]
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
@@ -593,65 +598,63 @@ class Model:
         x = self.embed(params, token)
         new_cache: Params = {"page_tables": pt}
 
-        if cfg.arch_type in ("dense", "vlm", "audio"):
-            def body(x, xs):
-                lp, lc = xs
+        def attend(p, h, pools, layer=None):
+            return attn.gqa_decode_paged(p, cfg, h, pools, pt, pos, live, uk, layer)
+
+        if cfg.arch_type in ("dense", "vlm", "audio", "moe"):
+            if cfg.arch_type == "moe":
+                for i in range(cfg.first_dense_layers):
+                    lp = params[f"dense_{i}"]
+                    h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
+                    a, nc = attend(lp["attn"], h, cache[f"dense_{i}"])
+                    x = x + a
+                    h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
+                    x = x + mlp_forward(lp["mlp"], h)
+                    new_cache[f"dense_{i}"] = nc
+
+            def body(carry, xs):
+                x, pools = carry
+                lp, i = xs
                 h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
-                a, nc = attn.gqa_decode_paged(
-                    lp["attn"], cfg, h, lc, pt, pos, live, uk
-                )
+                a, pools = attend(lp["attn"], h, pools, i)
                 x = x + a
                 h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
-                return x + mlp_forward(lp["mlp"], h), nc
+                if cfg.arch_type == "moe":
+                    out, _ = self._moe_fn(lp["moe"], cfg, h)
+                else:
+                    out = mlp_forward(lp["mlp"], h)
+                return (x + out, pools), None
 
-            x, ncs = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
-            new_cache["layers"] = ncs
-        elif cfg.arch_type == "moe":
-            for i in range(cfg.first_dense_layers):
-                lp = params[f"dense_{i}"]
-                h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
-                a, nc = attn.gqa_decode_paged(
-                    lp["attn"], cfg, h, cache[f"dense_{i}"], pt, pos, live, uk
-                )
-                x = x + a
-                h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
-                x = x + mlp_forward(lp["mlp"], h)
-                new_cache[f"dense_{i}"] = nc
-
-            def body(x, xs):
-                lp, lc = xs
-                h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
-                a, nc = attn.gqa_decode_paged(
-                    lp["attn"], cfg, h, lc, pt, pos, live, uk
-                )
-                x = x + a
-                h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
-                out, _ = self._moe_fn(lp["moe"], cfg, h)
-                return x + out, nc
-
-            x, ncs = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
-            new_cache["layers"] = ncs
+            pools = cache["layers"]
+            n = jax.tree.leaves(pools)[0].shape[0]
+            (x, pools), _ = jax.lax.scan(
+                body, (x, pools), (params["layers"], jnp.arange(n))
+            )
+            new_cache["layers"] = pools
         elif cfg.arch_type == "hybrid":
             shared = params["shared_attn"]
+            ssm_caches = {k: v for k, v in cache["layers"].items() if k != "attn"}
 
-            def body(x, xs):
-                lp, lc = xs
+            def body(carry, xs):
+                x, pools = carry
+                lp, lc, i = xs
                 nc = {}
-                for i in range(cfg.shared_attn_every):
-                    mp = lp[f"mamba_{i}"]
+                for j in range(cfg.shared_attn_every):
+                    mp = lp[f"mamba_{j}"]
                     h = rmsnorm(x, mp["ln"], cfg.norm_eps)
-                    y, c = ssm_mod.ssm_decode(mp, cfg, h, lc[f"mamba_{i}"], live)
+                    y, c = ssm_mod.ssm_decode(mp, cfg, h, lc[f"mamba_{j}"], live)
                     x = x + y
-                    nc[f"mamba_{i}"] = c
+                    nc[f"mamba_{j}"] = c
                 h = rmsnorm(x, shared["ln"], cfg.norm_eps)
-                a, c = attn.gqa_decode_paged(
-                    shared, cfg, h, lc["attn"], pt, pos, live, uk
-                )
-                nc["attn"] = c
-                return x + a, nc
+                a, pools = attend(shared, h, pools, i)
+                return (x + a, pools), nc
 
-            x, ncs = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
-            new_cache["layers"] = ncs
+            n = cfg.num_layers // cfg.shared_attn_every
+            (x, pools), ncs = jax.lax.scan(
+                body, (x, cache["layers"]["attn"]),
+                (params["layers"], ssm_caches, jnp.arange(n)),
+            )
+            new_cache["layers"] = {**ncs, "attn": pools}
         else:
             raise ValueError(cfg.arch_type)
         return self.logits(params, x), new_cache
@@ -661,24 +664,26 @@ class Model:
         self,
         cache: Params,
         prefill_cache: Params,
-        slot: int,
-        length: int,
-        page_ids: Optional[Sequence[int]] = None,
+        slot: jax.Array,
+        length: jax.Array,
+        page_row: Optional[jax.Array] = None,
     ) -> Params:
         """Scatter a batch-1 :meth:`prefill` cache into slot ``slot`` of an
         engine batch cache (flat :meth:`init_cache` layout, or paged
-        :meth:`init_paged_cache` layout when ``page_ids`` — the slot's
-        allocated pages, covering ≥ ``length`` tokens — is given).
+        :meth:`init_paged_cache` layout when ``page_row`` — the slot's
+        ``(max_pages,)`` page-table row, covering ≥ ``length`` tokens — is
+        given).
 
-        ``length`` is the true prompt length; prefill rows past it (chunk
-        padding) are never copied.  Runs eagerly on the host path: admit-time
-        work, no jit."""
+        ``length`` is the true prompt length; prefill rows past it (bucket
+        padding) are never copied.  ``slot`` and ``length`` may be traced, so
+        one jit serves every slot and prompt length of a prefill bucket; the
+        engine jits this with the cache donated, updating it in place."""
         return _scatter_node(
-            cache, prefill_cache, slot, length, False, page_ids
+            cache, prefill_cache, slot, length, False, page_row
         )
 
 
-# -- prefill-scatter helpers (host-side admit path) ---------------------------
+# -- prefill-scatter helpers (the engine's jitted admit path) -----------------
 
 
 def _scatter_leaf(eng, pre, slot, length, stacked):
@@ -687,43 +692,50 @@ def _scatter_leaf(eng, pre, slot, length, stacked):
     Leaves with a sequence axis (k/v/ckv/krope; engine seq length differs
     from the prefill's padded length) copy only the first ``length`` rows;
     fixed-shape state leaves (SSM conv/state) copy whole."""
-    b = 1 if stacked else 0
-    s = b + 1
-    if eng.ndim > s and eng.shape[s] != pre.shape[s]:
-        if stacked:
-            return eng.at[:, slot, :length].set(pre[:, 0, :length])
-        return eng.at[slot, :length].set(pre[0, :length])
     if stacked:
-        return eng.at[:, slot].set(pre[:, 0])
-    return eng.at[slot].set(pre[0])
+        return jax.vmap(lambda e, p: _scatter_leaf(e, p, slot, length, False))(
+            eng, pre
+        )
+    pre = pre.astype(eng.dtype)
+    if eng.ndim > 1 and eng.shape[1] != pre.shape[1]:
+        n = min(eng.shape[1], pre.shape[1])
+        row = jax.lax.dynamic_index_in_dim(eng, slot, 0, keepdims=False)
+        keep = (jnp.arange(n) < length).reshape((n,) + (1,) * (row.ndim - 1))
+        new = row.at[:n].set(jnp.where(keep, pre[0, :n], row[:n]))
+        return jax.lax.dynamic_update_index_in_dim(eng, new, slot, 0)
+    return jax.lax.dynamic_update_index_in_dim(eng, pre[0], slot, 0)
 
 
-def _scatter_pages(pool, pre, page_ids, length, stacked):
+def _scatter_pages(pool, pre, page_row, length, stacked):
     """Scatter the first ``length`` prefill k/v rows into the slot's pages:
-    token t lands in (page_ids[t // page_size], t % page_size)."""
-    ps = pool.shape[2 if stacked else 1]
-    t = jnp.arange(length)
-    pi = jnp.asarray(list(page_ids), jnp.int32)[t // ps]
-    off = t % ps
+    token t lands in (page_row[t // page_size], t % page_size).  The index
+    vector spans the whole padded prefill; rows past ``length`` aim at an
+    out-of-range page and are dropped."""
     if stacked:
-        return pool.at[:, pi, off].set(pre[:, 0, :length])
-    return pool.at[pi, off].set(pre[0, :length])
+        return jax.vmap(
+            lambda p, x: _scatter_pages(p, x, page_row, length, False)
+        )(pool, pre)
+    num_pages, ps = pool.shape[0], pool.shape[1]
+    t = jnp.arange(pre.shape[1])
+    pi = page_row[jnp.minimum(t // ps, page_row.shape[0] - 1)]
+    pi = jnp.where(t < length, pi, num_pages)
+    return pool.at[pi, t % ps].set(pre[0], mode="drop")
 
 
-def _scatter_node(eng, pre, slot, length, stacked, page_ids):
+def _scatter_node(eng, pre, slot, length, stacked, page_row):
     if isinstance(eng, dict):
         out = {}
         for key, sub in eng.items():
             if key == "page_tables":
                 out[key] = sub  # refreshed host-side by the engine
             elif key == "pool_k":
-                out[key] = _scatter_pages(sub, pre["k"], page_ids, length, stacked)
+                out[key] = _scatter_pages(sub, pre["k"], page_row, length, stacked)
             elif key == "pool_v":
-                out[key] = _scatter_pages(sub, pre["v"], page_ids, length, stacked)
+                out[key] = _scatter_pages(sub, pre["v"], page_row, length, stacked)
             else:
                 out[key] = _scatter_node(
                     sub, pre[key], slot, length, stacked or key == "layers",
-                    page_ids,
+                    page_row,
                 )
         return out
     return _scatter_leaf(eng, pre, slot, length, stacked)

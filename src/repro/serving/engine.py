@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.models import Model
 from repro.models.config import ModelConfig
+from repro.models.kernels_bridge import FLASH_TILE
 from repro.serving.paged_cache import OutOfPages, PagePool, page_bytes
 
 
@@ -78,6 +79,14 @@ def attn_layer_count(cfg: ModelConfig) -> int:
     if cfg.arch_type == "hybrid":
         return cfg.num_layers // cfg.shared_attn_every
     return cfg.num_layers
+
+
+def decode_fn(model: Model, kv_backend: str):
+    """The engine's jitted decode step for ``kv_backend`` (``"paged"`` or
+    ``"flat"``).  The cache is donated: the step writes the new token's k/v
+    into the pool in place instead of returning a second copy of it."""
+    step = model.decode_step_paged if kv_backend == "paged" else model.decode_step
+    return jax.jit(step, donate_argnums=(1,))
 
 
 def page_hbm_bytes(cfg: ModelConfig, page_size: int, dtype_bytes: int = 2) -> int:
@@ -158,26 +167,63 @@ class Engine:
             self.cache = model.init_paged_cache(
                 batch, num_pages, page_size, max_pages_per_req
             )
-            self._decode = jax.jit(model.decode_step_paged)
         else:
             self.pool = None
             self.cache = model.init_cache(batch, max_len)
-            self._decode = jax.jit(model.decode_step)
+        self._decode = decode_fn(model, backend)
         self._prefill = jax.jit(
             lambda p, toks, lens: model.prefill(p, tokens=toks, lengths=lens)
         )
+        # admission writes the prefill cache into the donated engine cache
+        self._scatter = jax.jit(model.scatter_prefill, donate_argnums=(0,))
         # Prompts are right-padded (exact — dt-masked SSM states, masked-out
         # attention rows, true-last-token logits; see Model.prefill) so the
         # jit'd prefill compiles one trace per length *bucket*, not per
         # distinct prompt/resume length.  SSM needs chunk alignment anyway;
         # MoE must see exact lengths because padded tokens would compete for
-        # expert capacity and perturb real-token outputs.
+        # expert capacity and perturb real-token outputs.  With kernels on,
+        # buckets are whole flash tiles so prefill never leaves the kernel.
         if cfg.arch_type in ("ssm", "hybrid"):
             self._pad_to = cfg.ssm_chunk
         elif cfg.arch_type == "moe":
             self._pad_to = 1
+        elif model.use_kernels:
+            self._pad_to = FLASH_TILE
         else:
             self._pad_to = 16
+
+    def padded_len(self, length: int) -> int:
+        """The prefill bucket a context of ``length`` tokens is padded to."""
+        return -(-length // self._pad_to) * self._pad_to
+
+    def compile(self, prompt_lens: Sequence[int]) -> Dict[str, Tuple[float, Any]]:
+        """Compile ahead of time every program that serving contexts of these
+        lengths runs: the decode step, and prefill + cache scatter for each
+        padded length.  Later calls with the same shapes reuse these
+        executables.  Returns ``{program: (compile seconds, compiled)}``."""
+        out: Dict[str, Tuple[float, Any]] = {}
+
+        def aot(name, fn, *args):
+            t0 = time.perf_counter()
+            compiled = fn.lower(*args).compile()
+            out[name] = (time.perf_counter() - t0, compiled)
+
+        toks = jnp.zeros((self.batch, 1), jnp.int32)
+        pos = jnp.full((self.batch,), -1, jnp.int32)
+        aot("decode", self._decode, self.params, self.cache, toks, pos)
+        row = (
+            jnp.zeros((self.pool.max_pages_per_req,), jnp.int32)
+            if self.pool is not None
+            else None
+        )
+        for pad in sorted({self.padded_len(n) for n in prompt_lens}):
+            ptoks = jax.ShapeDtypeStruct((1, pad), jnp.int32)
+            lens = jax.ShapeDtypeStruct((1,), jnp.int32)
+            aot(f"prefill[{pad}]", self._prefill, self.params, ptoks, lens)
+            _, pcache = jax.eval_shape(self._prefill, self.params, ptoks, lens)
+            aot(f"scatter[{pad}]", self._scatter, self.cache, pcache,
+                np.int32(0), np.int32(1), row)
+        return out
 
     # -- introspection --------------------------------------------------------
     def has_free_slot(self) -> bool:
@@ -224,19 +270,18 @@ class Engine:
                 self.pool.release(req.rid)
                 raise
         try:
-            pad = -(-L // self._pad_to) * self._pad_to
-            toks = np.zeros((1, pad), np.int32)
+            toks = np.zeros((1, self.padded_len(L)), np.int32)
             toks[0, :L] = ctx
             logits, pcache = self._prefill(
                 self.params, jnp.asarray(toks), jnp.asarray([L], jnp.int32)
             )
-            page_ids = (
-                self.pool.request(req.rid).page_ids
+            page_row = (
+                jnp.asarray(self.pool.tables([req.rid])[0][0])
                 if self.pool is not None
                 else None
             )
-            self.cache = self.model.scatter_prefill(
-                self.cache, pcache, slot, L, page_ids
+            self.cache = self._scatter(
+                self.cache, pcache, np.int32(slot), np.int32(L), page_row
             )
             self.slots[slot] = req
             self.slot_pos[slot] = L
@@ -285,15 +330,8 @@ class Engine:
                         live.remove(i)
             if not live:
                 return finished
-            self._refresh_page_tables()
-        toks = np.zeros((self.batch, 1), np.int32)
-        pos = np.full(self.batch, -1, np.int32)
-        for i in live:
-            toks[i, 0] = self.slots[i].out_tokens[-1]
-            pos[i] = self.slot_pos[i]
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos)
-        )
+        toks, pos = self.decode_inputs()
+        logits, self.cache = self._decode(self.params, self.cache, toks, pos)
         lg = np.asarray(logits.astype(jnp.float32))
         for i in live:
             req = self.slots[i]
@@ -305,6 +343,21 @@ class Engine:
         finished.extend(self._finished)
         self._finished = []
         return finished
+
+    def decode_inputs(self) -> Tuple[jax.Array, jax.Array]:
+        """``(tokens (B, 1), positions (B,))`` of the next decode step: each
+        live slot's last token at its own position, ``-1`` for idle slots.
+        Paged backend: the cache's page tables are refreshed first (the
+        caller has already grown every live slot's pages)."""
+        if self.pool is not None:
+            self._refresh_page_tables()
+        toks = np.zeros((self.batch, 1), np.int32)
+        pos = np.full(self.batch, -1, np.int32)
+        for i, req in enumerate(self.slots):
+            if req is not None:
+                toks[i, 0] = req.out_tokens[-1]
+                pos[i] = self.slot_pos[i]
+        return jnp.asarray(toks), jnp.asarray(pos)
 
     # -- internals ------------------------------------------------------------
     def _sample(

@@ -25,18 +25,22 @@ def rand(i, shape):
     ],
 )
 def test_paged_kernel_matches_ref(B, H, KV, D, num_pages, page_size, max_pages):
+    """The kernel reads one layer of a stacked (L, ...) pool."""
     rng = np.random.default_rng(0)
+    L, layer = 3, 1
     q = rand(0, (B, H, D))
-    pk = rand(1, (num_pages, page_size, KV, D))
-    pv = rand(2, (num_pages, page_size, KV, D))
+    pk = rand(1, (L, num_pages, page_size, KV, D))
+    pv = rand(2, (L, num_pages, page_size, KV, D))
     pt = jnp.asarray(
         rng.integers(0, num_pages, size=(B, max_pages)), jnp.int32
     )
     lengths = jnp.asarray(
         rng.integers(1, max_pages * page_size + 1, size=(B,)), jnp.int32
     )
-    out = paged_decode_attention(q, pk, pv, pt, lengths, interpret=True)
-    ref = paged_decode_attention_ref(q, pk, pv, pt, lengths)
+    out = paged_decode_attention(
+        q, pk, pv, pt, lengths, jnp.int32(layer), interpret=True
+    )
+    ref = paged_decode_attention_ref(q, pk[layer], pv[layer], pt, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5)
 
 

@@ -1,0 +1,111 @@
+"""Compile the serving path's TPU programs for a described (not attached)
+v5e chip, at ``phi4-mini-3.8b`` widths.
+
+The TPU compiler refuses what interpret mode accepts: block shapes that
+break the tiling rules, kernels over their fast-memory budget, and programs
+that do not fit the chip's HBM.  These cases compile the Pallas kernels with
+``interpret=False``, and the engine's donated paged decode step for batch
+8 × 4096 tokens, and pin that the step fits one chip with the KV pool
+updated in place.  Nothing runs, so they say nothing about results or time.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.paged_attention import paged_decode_attention
+from repro.models import Model
+from repro.serving.engine import decode_fn
+
+HBM_BYTES = 16 * 10**9  # one v5e chip
+BATCH, MAX_LEN, PAGE = 8, 4096, 16
+MAX_PAGES = MAX_LEN // PAGE
+NUM_PAGES = BATCH * MAX_PAGES  # the engine's default pool: a full context per slot
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip cannot be read back from the
+    # persistent cache without one: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_paged_decode_kernel_compiles(one_chip):
+    cfg = get_config("phi4-mini-3.8b")
+    H, KV, D, L = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.num_layers
+    bf16 = jnp.bfloat16
+    pool = _sds((L, NUM_PAGES, PAGE, KV, D), bf16, one_chip)
+    args = (
+        _sds((BATCH, H, D), bf16, one_chip), pool, pool,
+        _sds((BATCH, MAX_PAGES), jnp.int32, one_chip),
+        _sds((BATCH,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+    )
+    fn = jax.jit(lambda *a: paged_decode_attention(*a, interpret=False))
+    assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+
+
+def test_flash_attention_kernel_compiles(one_chip):
+    cfg = get_config("phi4-mini-3.8b")
+    S, D = 1024, cfg.head_dim
+    q = _sds((1, cfg.num_heads, S, D), jnp.bfloat16, one_chip)
+    kv = _sds((1, cfg.num_kv_heads, S, D), jnp.bfloat16, one_chip)
+    fn = jax.jit(lambda q, k, v: flash_attention_bhsd(q, k, v, interpret=False))
+    assert "tpu_custom_call" in fn.lower(q, kv, kv).compile().as_text()
+
+
+@pytest.mark.parametrize("use_kernels", [False, True], ids=["jnp", "kernel"])
+def test_engine_decode_step_fits_one_chip(one_chip, monkeypatch, use_kernels):
+    """The engine's donated paged decode step at batch 8 × 4096: the pool is
+    aliased from input to output (updated in place, never copied), and
+    arguments + outputs − aliased + temporaries fit the chip's HBM."""
+    if use_kernels:
+        # a CPU process runs kernels in interpret mode; compile the real one
+        monkeypatch.setattr(ops, "_interpret", lambda: False)
+    model = Model(get_config("phi4-mini-3.8b"), remat=False, use_kernels=use_kernels)
+
+    def on_chip(x):
+        return _sds(x.shape, x.dtype, one_chip)
+
+    params = jax.tree.map(on_chip, model.init(None, abstract=True)[0])
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init_paged_cache(BATCH, NUM_PAGES, PAGE, MAX_PAGES)
+    ))
+    compiled = decode_fn(model, "paged").lower(
+        params, cache,
+        _sds((BATCH, 1), jnp.int32, one_chip), _sds((BATCH,), jnp.int32, one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache["layers"]))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+    assert ("tpu_custom_call" in compiled.as_text()) == use_kernels
