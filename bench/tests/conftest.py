@@ -1,0 +1,14 @@
+"""Tests of the benchmark's own code, on the CPU at small sizes.
+
+Run from the repository root:  python -m pytest -q bench/tests
+"""
+
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
