@@ -203,8 +203,8 @@ def run(
     log(
         f"one-chip smoke numbers, not benchmark results: {stats.served} "
         f"requests, {stats.tokens} tokens in {stats.wall_s} s = "
-        f"{stats.tokens / stats.wall_s} tokens/s; TTFT median (from "
-        f"admission) {np.median(stats.ttft_s)} s, TPOT median "
+        f"{stats.tokens / stats.wall_s} tokens/s; TTFT median "
+        f"{np.median(stats.ttft_s)} s, TPOT median "
         f"{np.median(stats.tpot_s)} s; preempted {stats.preempted}, "
         f"refused {stats.refused}"
     )

@@ -121,4 +121,5 @@ def flash_attention_bhsd(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -138,5 +138,6 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_tables.astype(jnp.int32), lengths.astype(jnp.int32), layer,
       q, pool_k, pool_v)

@@ -35,17 +35,28 @@ what required by SLOs" (§7).  :func:`run_closed_loop` closes the paper's
 §8.3 loop: measured throughput feeds a
 :class:`~repro.core.online_profiles.MeasuredProfile` so the optimizer
 consumes production-corrected profiles.
+
+Host phases are observable on the wall clock.  Each phase of
+:meth:`Engine.step` and :meth:`Engine.admit` runs inside an ``engine.<phase>``
+span (:func:`jax.profiler.TraceAnnotation`), which lands in the profiler's
+host trace, on the device trace's clock, when a profiler is attached; its
+``time.perf_counter`` duration goes into :attr:`Engine.last_phases`,
+replaced by every call.  :attr:`Engine.counters` counts decode steps,
+preemptions and admission refusals where they happen.  Request stamps use
+the same ``time.perf_counter`` clock.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import Model
 from repro.models.config import ModelConfig
@@ -63,6 +74,9 @@ class Request:
     prompt: np.ndarray  # (prompt_len,) int32
     max_new_tokens: int
     out_tokens: List[int] = dataclasses.field(default_factory=list)
+    # time.perf_counter stamps: ``submitted_s`` by the caller as the request
+    # is queued (the engine never sets it; TTFT is counted from it), the
+    # other two by the engine
     submitted_s: float = 0.0
     first_token_s: float = 0.0
     finished_s: float = 0.0
@@ -98,6 +112,19 @@ def page_hbm_bytes(cfg: ModelConfig, page_size: int, dtype_bytes: int = 2) -> in
     )
 
 
+@contextlib.contextmanager
+def _phase(phases: Dict[str, float], name: str) -> Iterator[None]:
+    """One host phase of an engine call: an ``engine.<name>`` span in the
+    profiler's trace (a no-op with no profiler attached), and its wall time
+    added to ``phases[name]``."""
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation("engine." + name):
+            yield
+    finally:
+        phases[name] = phases.get(name, 0.0) + time.perf_counter() - t0
+
+
 class Engine:
     def __init__(
         self,
@@ -120,7 +147,12 @@ class Engine:
         self.max_len = max_len
         self.temperature = temperature
         self.top_k = top_k
-        self.steps = 0
+        # events counted where they happen: decode steps run, requests
+        # preempted, admissions refused with OutOfPages
+        self.counters: Dict[str, int] = dict.fromkeys(
+            ("steps", "preempted", "refused"), 0)
+        # seconds per host phase of the last step() or admit() call
+        self.last_phases: Dict[str, float] = {}
         self.slots: List[Optional[Request]] = [None] * batch
         # per-slot context length; -1 marks an idle slot (the decode-side
         # convention: negative position => masked cache writes)
@@ -171,9 +203,11 @@ class Engine:
             self.pool = None
             self.cache = model.init_cache(batch, max_len)
         self._decode = decode_fn(model, backend)
-        self._prefill = jax.jit(
-            lambda p, toks, lens: model.prefill(p, tokens=toks, lengths=lens)
-        )
+
+        def prefill(p, toks, lens):
+            return model.prefill(p, tokens=toks, lengths=lens)
+
+        self._prefill = jax.jit(prefill)
         # admission writes the prefill cache into the donated engine cache
         self._scatter = jax.jit(model.scatter_prefill, donate_argnums=(0,))
         # Prompts are right-padded (exact — dt-masked SSM states, masked-out
@@ -233,6 +267,11 @@ class Engine:
     def num_live(self) -> int:
         return sum(s is not None for s in self.slots)
 
+    @property
+    def steps(self) -> int:
+        """Decode steps run so far (``counters["steps"]``)."""
+        return self.counters["steps"]
+
     def take_preempted(self) -> List[Request]:
         """Requests evicted on pool exhaustion since the last call; re-admit
         them (their generated tokens resume from the prompt) once capacity
@@ -248,7 +287,10 @@ class Engine:
 
         Raises :class:`OutOfPages` (paged backend) when the pool cannot hold
         the context plus one decode token — the admission-control signal; the
-        request is left untouched for the caller to retry later."""
+        request is left untouched for the caller to retry later.
+
+        The caller stamps ``req.submitted_s`` (``time.perf_counter``) when it
+        queues the request; an unstamped request's TTFT has no start."""
         ctx = np.asarray(req.prompt, np.int32)
         if req.out_tokens:  # resuming after preemption
             ctx = np.concatenate([ctx, np.asarray(req.out_tokens, np.int32)])
@@ -260,52 +302,59 @@ class Engine:
                 f"context length {L} does not fit max_len={self.max_len}"
             )
         slot = self.slots.index(None)
-        if self.pool is not None:
-            self.pool.admit(req.rid)
+        self.last_phases = phases = {}
+        with TraceAnnotation("engine.admit", rid=req.rid, ctx=L):
+            with _phase(phases, "reserve"):
+                if self.pool is not None:
+                    self.pool.admit(req.rid)
+                    try:
+                        # context + room for the first decode write (so an
+                        # admitted request can always take at least one step)
+                        self.pool.append_tokens(req.rid, L + 1)
+                    except OutOfPages:
+                        self.pool.release(req.rid)
+                        self.counters["refused"] += 1
+                        raise
             try:
-                # context + room for the first decode write (so an admitted
-                # request can always take at least one step)
-                self.pool.append_tokens(req.rid, L + 1)
-            except OutOfPages:
-                self.pool.release(req.rid)
+                with _phase(phases, "dispatch"):
+                    toks = np.zeros((1, self.padded_len(L)), np.int32)
+                    toks[0, :L] = ctx
+                    logits, pcache = self._prefill(
+                        self.params, jnp.asarray(toks), jnp.asarray([L], jnp.int32)
+                    )
+                    page_row = (
+                        jnp.asarray(self.pool.tables([req.rid])[0][0])
+                        if self.pool is not None
+                        else None
+                    )
+                    self.cache = self._scatter(
+                        self.cache, pcache, np.int32(slot), np.int32(L), page_row
+                    )
+                    logits = logits.astype(jnp.float32)
+                self.slots[slot] = req
+                self.slot_pos[slot] = L
+                with _phase(phases, "wait"):
+                    logits.block_until_ready()
+                with _phase(phases, "fetch"):
+                    row = np.asarray(logits)[0, 0]
+                with _phase(phases, "sample"):
+                    req.out_tokens.append(self._sample(row, rng))
+                    if req.first_token_s == 0.0:
+                        req.first_token_s = time.perf_counter()
+            except BaseException:
+                # prefill/scatter/sampling failed after the pages were
+                # reserved: undo the reservation (free list byte-identical,
+                # stale rid entry dropped so a retry of the same rid
+                # re-admits cleanly) and free the slot — the OutOfPages
+                # contract says a failed admission leaves the engine
+                # untouched.
+                self.slots[slot] = None
+                self.slot_pos[slot] = -1
+                if self.pool is not None:
+                    self.pool.abort(req.rid)
                 raise
-        try:
-            toks = np.zeros((1, self.padded_len(L)), np.int32)
-            toks[0, :L] = ctx
-            logits, pcache = self._prefill(
-                self.params, jnp.asarray(toks), jnp.asarray([L], jnp.int32)
-            )
-            page_row = (
-                jnp.asarray(self.pool.tables([req.rid])[0][0])
-                if self.pool is not None
-                else None
-            )
-            self.cache = self._scatter(
-                self.cache, pcache, np.int32(slot), np.int32(L), page_row
-            )
-            self.slots[slot] = req
-            self.slot_pos[slot] = L
-            if req.submitted_s == 0.0:
-                req.submitted_s = time.monotonic()
-            first = self._sample(
-                np.asarray(logits.astype(jnp.float32))[0, 0], rng
-            )
-            req.out_tokens.append(first)
-            if req.first_token_s == 0.0:
-                req.first_token_s = time.monotonic()
-        except BaseException:
-            # prefill/scatter/sampling failed after the pages were reserved:
-            # undo the reservation (free list byte-identical, stale rid entry
-            # dropped so a retry of the same rid re-admits cleanly) and free
-            # the slot — the OutOfPages contract says a failed admission
-            # leaves the engine untouched.
-            self.slots[slot] = None
-            self.slot_pos[slot] = -1
-            if self.pool is not None:
-                self.pool.abort(req.rid)
-            raise
-        if req.done:
-            self._finish(slot)
+            if req.done:
+                self._finish(slot)
         return slot
 
     # -- decode ---------------------------------------------------------------
@@ -315,31 +364,42 @@ class Engine:
         step).  Paged backend: slots that cannot allocate their next token's
         page are preempted first (see :meth:`take_preempted`)."""
         finished, self._finished = self._finished, []
+        self.last_phases = phases = {}
         live = [i for i, s in enumerate(self.slots) if s is not None]
         if not live:
             return finished
-        if self.pool is not None:
-            for i in list(live):
-                req = self.slots[i]
-                need = int(self.slot_pos[i]) + 1 - self.pool.request(req.rid).length
-                if need > 0:
-                    try:
-                        self.pool.append_tokens(req.rid, need)
-                    except OutOfPages:
-                        self._preempt(i)
-                        live.remove(i)
+        with TraceAnnotation("engine.step", rows=len(live)):
+            with _phase(phases, "grow"):
+                if self.pool is not None:
+                    for i in list(live):
+                        req = self.slots[i]
+                        need = (int(self.slot_pos[i]) + 1
+                                - self.pool.request(req.rid).length)
+                        if need > 0:
+                            try:
+                                self.pool.append_tokens(req.rid, need)
+                            except OutOfPages:
+                                self._preempt(i)
+                                live.remove(i)
             if not live:
                 return finished
-        toks, pos = self.decode_inputs()
-        logits, self.cache = self._decode(self.params, self.cache, toks, pos)
-        lg = np.asarray(logits.astype(jnp.float32))
-        for i in live:
-            req = self.slots[i]
-            self.slot_pos[i] += 1
-            req.out_tokens.append(self._sample(lg[i, 0], rng))
-            if req.done or self.slot_pos[i] >= self.max_len:
-                self._finish(i)
-        self.steps += 1
+            with _phase(phases, "inputs"):
+                toks, pos = self.decode_inputs()
+            with _phase(phases, "dispatch"):
+                logits, self.cache = self._decode(self.params, self.cache, toks, pos)
+                logits = logits.astype(jnp.float32)
+            with _phase(phases, "wait"):
+                logits.block_until_ready()
+            with _phase(phases, "fetch"):
+                lg = np.asarray(logits)
+            with _phase(phases, "sample"):
+                for i in live:
+                    req = self.slots[i]
+                    self.slot_pos[i] += 1
+                    req.out_tokens.append(self._sample(lg[i, 0], rng))
+                    if req.done or self.slot_pos[i] >= self.max_len:
+                        self._finish(i)
+        self.counters["steps"] += 1
         finished.extend(self._finished)
         self._finished = []
         return finished
@@ -384,7 +444,7 @@ class Engine:
 
     def _finish(self, slot: int) -> None:
         req = self.slots[slot]
-        req.finished_s = time.monotonic()
+        req.finished_s = time.perf_counter()
         self.slots[slot] = None
         self.slot_pos[slot] = -1
         if self.pool is not None:
@@ -404,6 +464,7 @@ class Engine:
         self.slot_pos[slot] = -1
         self.pool.release(req.rid)
         self._preempted.append(req)
+        self.counters["preempted"] += 1
 
     def _refresh_page_tables(self) -> None:
         rids = [s.rid if s is not None else None for s in self.slots]
@@ -418,9 +479,9 @@ class ServeStats:
     preempted: int = 0
     refused: int = 0  # OutOfPages admission refusals (request stays pending)
     wall_s: float = 0.0
-    # per-request latency observations (wall clock): time-to-first-token and
-    # mean time-per-output-token — the measured twins of the token-level
-    # serving model's TTFT/TPOT metrics (repro.sim.servemodel)
+    # per-request latency observations (wall clock): time-to-first-token
+    # from submission and mean time-per-output-token — the measured twins of
+    # the token-level serving model's TTFT/TPOT metrics (repro.sim.servemodel)
     ttft_s: List[float] = dataclasses.field(default_factory=list)
     tpot_s: List[float] = dataclasses.field(default_factory=list)
 
@@ -468,11 +529,19 @@ def run_closed_loop(
     request pending until capacity frees up.  When ``measured`` (a
     :class:`~repro.core.online_profiles.MeasuredProfile`) plus ``service``
     and ``size`` are given, the measured throughput is fed back into the
-    profile — the paper's §8.3 production-measurement loop."""
+    profile — the paper's §8.3 production-measurement loop.
+
+    Every request not yet stamped gets ``submitted_s`` as it enters the
+    pending queue, so TTFT counts the queue wait.  ``preempted`` and
+    ``refused`` are the engine's own counters over the run."""
     rng = np.random.default_rng(seed)
-    pending = list(requests)
     stats = ServeStats()
-    t0 = time.monotonic()
+    before = dict(engine.counters)
+    t0 = time.perf_counter()
+    for req in requests:
+        if not req.submitted_s:
+            req.submitted_s = t0
+    pending = list(requests)
     while stats.served < len(requests):
         admitted = False
         # first-fit admission: a request the pool cannot hold right now must
@@ -483,7 +552,6 @@ def run_closed_loop(
             try:
                 engine.admit(req, rng)
             except OutOfPages:
-                stats.refused += 1
                 continue
             pending.remove(req)
             admitted = True
@@ -499,7 +567,6 @@ def run_closed_loop(
                         / (len(req.out_tokens) - 1)
                     )
         preempted = engine.take_preempted()
-        stats.preempted += len(preempted)
         pending = preempted + pending
         # Stuck only if this iteration made no progress of any kind —
         # a preemption frees pages the next admission pass can use.
@@ -509,7 +576,9 @@ def run_closed_loop(
                 f"requests {[r.rid for r in pending]} cannot be admitted: "
                 f"page pool too small for their contexts"
             )
-    stats.wall_s = time.monotonic() - t0
+    stats.wall_s = time.perf_counter() - t0
+    stats.preempted = engine.counters["preempted"] - before["preempted"]
+    stats.refused = engine.counters["refused"] - before["refused"]
     if measured is not None and service is not None and size is not None:
         if stats.wall_s > 0:
             measured.observe(service, size, engine.batch, stats.throughput)

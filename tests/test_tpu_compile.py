@@ -10,6 +10,7 @@ updated in place.  Nothing runs, so they say nothing about results or time.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,36 @@ def test_flash_attention_kernel_compiles(one_chip):
     kv = _sds((1, cfg.num_kv_heads, S, D), jnp.bfloat16, one_chip)
     fn = jax.jit(lambda q, k, v: flash_attention_bhsd(q, k, v, interpret=False))
     assert "tpu_custom_call" in fn.lower(q, kv, kv).compile().as_text()
+
+
+def _kernel_names(hlo_text):
+    """The instruction names, without their ``.N`` suffix, of the Pallas
+    calls in a compiled program: what a device trace names their events."""
+    return {re.match(r"\s*(?:ROOT\s+)?%?([^\s=.]+)", line).group(1)
+            for line in hlo_text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
+def test_kernels_carry_the_names_the_trace_is_matched_by(one_chip):
+    """Each kernel names its own call, whatever jit wraps it: the benchmark's
+    rooflines find their events in a trace by these names."""
+    cfg = get_config("phi4-mini-3.8b")
+    H, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    bf16 = jnp.bfloat16
+    pool = _sds((2, 64, PAGE, KV, D), bf16, one_chip)
+    q = _sds((1, H, 256, D), bf16, one_chip)
+    kv = _sds((1, KV, 256, D), bf16, one_chip)
+
+    def both(qd, pk, pv, tables, lens, layer, q, k, v):
+        return (paged_decode_attention(qd, pk, pv, tables, lens, layer, interpret=False),
+                flash_attention_bhsd(q, k, v, interpret=False))
+
+    compiled = jax.jit(both).lower(
+        _sds((BATCH, H, D), bf16, one_chip), pool, pool,
+        _sds((BATCH, 8), jnp.int32, one_chip), _sds((BATCH,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), q, kv, kv,
+    ).compile()
+    assert _kernel_names(compiled.as_text()) == {"paged_decode_attention", "flash_attention"}
 
 
 @pytest.mark.parametrize("use_kernels", [False, True], ids=["jnp", "kernel"])
