@@ -42,8 +42,8 @@ span (:func:`jax.profiler.TraceAnnotation`), which lands in the profiler's
 host trace, on the device trace's clock, when a profiler is attached; its
 ``time.perf_counter`` duration goes into :attr:`Engine.last_phases`,
 replaced by every call.  :attr:`Engine.counters` counts decode steps,
-preemptions and admission refusals where they happen.  Request stamps use
-the same ``time.perf_counter`` clock.
+preemptions, admission refusals and the paged kernel's blocks where they
+happen.  Request stamps use the same ``time.perf_counter`` clock.
 """
 
 from __future__ import annotations
@@ -59,6 +59,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.models import Model
+from repro.models.common import DTYPES
 from repro.models.config import ModelConfig
 from repro.models.kernels_bridge import FLASH_TILE
 from repro.serving.paged_cache import OutOfPages, PagePool, page_bytes
@@ -148,9 +149,10 @@ class Engine:
         self.temperature = temperature
         self.top_k = top_k
         # events counted where they happen: decode steps run, requests
-        # preempted, admissions refused with OutOfPages
+        # preempted, admissions refused with OutOfPages, and the blocks of
+        # pages the paged kernel walks per layer (see decode_inputs)
         self.counters: Dict[str, int] = dict.fromkeys(
-            ("steps", "preempted", "refused"), 0)
+            ("steps", "preempted", "refused", "kv_blocks"), 0)
         # seconds per host phase of the last step() or admit() call
         self.last_phases: Dict[str, float] = {}
         self.slots: List[Optional[Request]] = [None] * batch
@@ -199,6 +201,12 @@ class Engine:
             self.cache = model.init_paged_cache(
                 batch, num_pages, page_size, max_pages_per_req
             )
+            # lazy: kernels are optional at import
+            from repro.kernels.paged_attention import pages_per_block
+
+            self.block_tokens = page_size * pages_per_block(
+                page_size, cfg.num_kv_heads, cfg.head_dim,
+                jnp.dtype(DTYPES[cfg.dtype]).itemsize, max_pages_per_req)
         else:
             self.pool = None
             self.cache = model.init_cache(batch, max_len)
@@ -408,7 +416,10 @@ class Engine:
         """``(tokens (B, 1), positions (B,))`` of the next decode step: each
         live slot's last token at its own position, ``-1`` for idle slots.
         Paged backend: the cache's page tables are refreshed first (the
-        caller has already grown every live slot's pages)."""
+        caller has already grown every live slot's pages), and
+        ``counters["kv_blocks"]`` grows by the blocks the paged kernel walks
+        in each layer: ``cdiv(length, block_tokens)`` per row, where a row's
+        length is its position + 1 (0 for an idle slot)."""
         if self.pool is not None:
             self._refresh_page_tables()
         toks = np.zeros((self.batch, 1), np.int32)
@@ -417,6 +428,8 @@ class Engine:
             if req is not None:
                 toks[i, 0] = req.out_tokens[-1]
                 pos[i] = self.slot_pos[i]
+        if self.pool is not None:
+            self.counters["kv_blocks"] += int(np.sum(-(-(pos + 1) // self.block_tokens)))
         return jnp.asarray(toks), jnp.asarray(pos)
 
     # -- internals ------------------------------------------------------------

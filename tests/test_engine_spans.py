@@ -2,9 +2,9 @@
 
 ``Engine.step`` and ``Engine.admit`` time each host phase into
 ``last_phases`` and wrap it in an ``engine.<phase>`` profiler span;
-``Engine.counters`` counts events where they happen; ``run_closed_loop``
-reads its preemptions and refusals from those counters and times TTFT from
-submission.
+``Engine.counters`` counts events where they happen, and the blocks the
+paged kernel walks; ``run_closed_loop`` reads its preemptions and refusals
+from those counters and times TTFT from submission.
 """
 
 import glob
@@ -17,6 +17,7 @@ import pytest
 from jax.profiler import TraceAnnotation
 
 import test_engine_ragged as ragged
+from repro.kernels import paged_attention
 from repro.serving import Engine, OutOfPages, Request, run_closed_loop
 
 STEP_PHASES = {"grow", "inputs", "dispatch", "wait", "fetch", "sample"}
@@ -95,12 +96,32 @@ def test_counters_count_what_the_callers_see(setup):
         steps += live > len(back)
         pending = back + pending
     c = eng.counters
-    assert set(c) == {"steps", "preempted", "refused"}
+    assert set(c) == {"steps", "preempted", "refused", "kv_blocks"}
     assert all(isinstance(v, int) for v in c.values())
     assert (c["steps"], c["preempted"], c["refused"]) == (steps, preempted, refused)
     assert eng.steps == c["steps"]
     assert finished == n and (refused > 0 if setup is REFUSING else preempted > 0)
     assert all(r.done for r in reqs)
+
+
+def test_kv_blocks_counts_the_blocks_the_paged_kernel_walks(monkeypatch):
+    """Each step adds cdiv(length, block_tokens) for every live row, a row's
+    length being its position + 1; a step with no live row adds nothing."""
+    # 1 KiB of K a block: two of this model's 512 B pages, so rows span blocks
+    monkeypatch.setattr(paged_attention, "BLOCK_BYTES", 1024)
+    eng = _engine(batch=3, page_size=4)
+    assert eng.block_tokens == 8
+    for i, p in enumerate(ragged.make_prompts(eng.cfg, (3, 8, 13))):
+        eng.admit(Request(rid=i, prompt=p, max_new_tokens=6))
+    want = 0
+    while eng.num_live:
+        lengths = [int(eng.slot_pos[i]) + 1 for i, s in enumerate(eng.slots) if s]
+        want += sum(-(-n // eng.block_tokens) for n in lengths)
+        eng.step()
+        assert eng.counters["kv_blocks"] == want
+    assert want > 3 * eng.steps  # some rows took more than one block
+    eng.step()
+    assert eng.counters["kv_blocks"] == want
 
 
 @pytest.mark.parametrize("setup", [REFUSING, PREEMPTING], ids=["refusing", "preempting"])

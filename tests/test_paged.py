@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.paged_attention import paged_decode_attention
+from repro.kernels.paged_attention import (
+    BLOCK_BYTES, paged_decode_attention, pages_per_block,
+)
 from repro.kernels.ref import paged_decode_attention_ref
 from repro.serving.paged_cache import OutOfPages, PagePool
 
@@ -16,32 +18,73 @@ def rand(i, shape):
 
 
 @pytest.mark.parametrize(
-    "B,H,KV,D,num_pages,page_size,max_pages",
+    "B,H,KV,D,num_pages,page_size,max_pages,dtype,lengths",
     [
-        (2, 4, 2, 64, 8, 16, 3),
-        (3, 8, 2, 64, 16, 32, 4),
-        (1, 8, 1, 128, 8, 64, 2),  # MQA
-        (2, 4, 4, 32, 12, 8, 6),   # MHA small pages
+        pytest.param(2, 4, 2, 64, 8, 16, 3, "float32", None, id="2-4-2-64-8-16-3"),
+        pytest.param(3, 8, 2, 64, 16, 32, 4, "float32", None, id="3-8-2-64-16-32-4"),
+        pytest.param(1, 8, 1, 128, 8, 64, 2, "float32", None, id="1-8-1-128-8-64-2"),  # MQA
+        pytest.param(2, 4, 4, 32, 12, 8, 6, "float32", None,
+                     id="2-4-4-32-12-8-6"),  # MHA small pages
+        # the benchmark cells' head shapes at 16-token pages; the lengths are
+        # idle rows first and between live ones, exactly one block, one block
+        # + 1 token and a full row.
+        # phi4-mini GQA 24/8: 4 float32 pages (64 tokens) or 8 bf16 pages a block
+        pytest.param(5, 24, 8, 128, 12, 16, 10, "float32", (0, 64, 65, 0, 160),
+                     id="phi4-float32"),
+        pytest.param(5, 24, 8, 128, 12, 16, 18, "bfloat16", (0, 128, 129, 0, 288),
+                     id="phi4-bfloat16"),
+        # granite-20b MQA 48/1: 32 float32 pages (512 tokens) or 64 bf16 pages
+        pytest.param(5, 48, 1, 128, 24, 16, 40, "float32", (0, 512, 513, 0, 640),
+                     id="granite-float32"),
+        pytest.param(5, 48, 1, 128, 24, 16, 72, "bfloat16", (0, 1024, 1025, 0, 1152),
+                     id="granite-bfloat16"),
     ],
 )
-def test_paged_kernel_matches_ref(B, H, KV, D, num_pages, page_size, max_pages):
-    """The kernel reads one layer of a stacked (L, ...) pool."""
+def test_paged_kernel_matches_ref(B, H, KV, D, num_pages, page_size, max_pages,
+                                  dtype, lengths):
+    """The kernel reads one layer of a stacked (L, ...) pool.  With pinned
+    lengths, rows 1 and 4 share every page id, and the page that holds row
+    2's one token past its first block has V of 64 everywhere, so leaving
+    that token out moves the row's output past any rounding."""
     rng = np.random.default_rng(0)
     L, layer = 3, 1
-    q = rand(0, (B, H, D))
-    pk = rand(1, (L, num_pages, page_size, KV, D))
-    pv = rand(2, (L, num_pages, page_size, KV, D))
-    pt = jnp.asarray(
-        rng.integers(0, num_pages, size=(B, max_pages)), jnp.int32
-    )
-    lengths = jnp.asarray(
-        rng.integers(1, max_pages * page_size + 1, size=(B,)), jnp.int32
-    )
+    dt = jnp.dtype(dtype)
+    q = rand(0, (B, H, D)).astype(dt)
+    pk = rand(1, (L, num_pages, page_size, KV, D)).astype(dt)
+    pv = rand(2, (L, num_pages, page_size, KV, D)).astype(dt)
+    if lengths is None:
+        pt = rng.integers(0, num_pages, size=(B, max_pages))
+        lengths = rng.integers(1, max_pages * page_size + 1, size=(B,))
+    else:
+        pt = rng.integers(0, num_pages - 1, size=(B, max_pages))
+        pt[1] = pt[4]
+        pt[2, (lengths[2] - 1) // page_size] = num_pages - 1
+        pv = pv.at[layer, num_pages - 1].set(64)
+    pt, lengths = jnp.asarray(pt, jnp.int32), jnp.asarray(lengths, jnp.int32)
     out = paged_decode_attention(
         q, pk, pv, pt, lengths, jnp.int32(layer), interpret=True
     )
-    ref = paged_decode_attention_ref(q, pk[layer], pv[layer], pt, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5)
+    f32 = jnp.float32
+    ref = paged_decode_attention_ref(q.astype(f32), pk[layer].astype(f32),
+                                     pv[layer].astype(f32), pt, lengths)
+    idle = np.asarray(lengths) == 0
+    out, ref = np.asarray(out.astype(f32)), np.asarray(ref)
+    assert not out[idle].any()  # an idle row writes zeros
+    # float32: the reference's own rounding; bf16: one rounding of the output
+    tol = 3e-5 if dt == f32 else float(jnp.finfo(dt).eps)
+    np.testing.assert_allclose(out[~idle], ref[~idle], atol=tol, rtol=tol)
+
+
+def test_pages_per_block_fills_a_block_from_the_page_shape():
+    """One block moves BLOCK_BYTES of K: 8 of phi4-mini's 32 KiB pages (GQA
+    8 × 128, bf16), 64 of granite-20b's 4 KiB MQA pages; never more pages
+    than a row holds, never fewer than one."""
+    assert BLOCK_BYTES == 256 * 1024
+    assert pages_per_block(16, 8, 128, 2, 128) == 8
+    assert pages_per_block(16, 1, 128, 2, 128) == 64
+    assert pages_per_block(16, 1, 128, 2, 40) == 40
+    assert pages_per_block(16, 8, 128, 4, 128) == 4
+    assert pages_per_block(1024, 8, 128, 4, 128) == 1
 
 
 class TestPagePool:

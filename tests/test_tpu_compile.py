@@ -5,12 +5,15 @@ The TPU compiler refuses what interpret mode accepts: block shapes that
 break the tiling rules, kernels over their fast-memory budget, and programs
 that do not fit the chip's HBM.  These cases compile the Pallas kernels with
 ``interpret=False``, and the engine's donated paged decode step for batch
-8 × 4096 tokens, and pin that the step fits one chip with the KV pool
-updated in place.  Nothing runs, so they say nothing about results or time.
+8 × 4096 tokens and at the benchmark cells' sizes, and pin that the step fits
+one chip with the KV pool updated in place.  Nothing runs, so they say
+nothing about results or time.
 """
 
+import json
 import os
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,7 @@ from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.paged_attention import paged_decode_attention
 from repro.models import Model
+from repro.models.config import ModelConfig
 from repro.serving.engine import decode_fn
 
 HBM_BYTES = 16 * 10**9  # one v5e chip
@@ -58,6 +62,21 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+# the benchmark cells (bench/cells, bench/configs): rows, pool pages and
+# max_len 2048 in 16-token pages, and the decode step's temporaries before the
+# kernel walked live pages only (granite's: two copies of its MQA pool)
+CELLS = {
+    "phi4-mini-3.8b": (160, 3480, 387_072),
+    "granite-20b-pp4": (256, 16384, 3_493_633_536),
+}
+CELL_MAX_PAGES = 2048 // PAGE
+BENCH_CONFIGS = Path(__file__).resolve().parent.parent / "bench" / "configs"
+
+
+def _cell_config(name):
+    return ModelConfig(**json.loads((BENCH_CONFIGS / f"{name}.json").read_text())["model"])
+
+
 def test_paged_decode_kernel_compiles(one_chip):
     cfg = get_config("phi4-mini-3.8b")
     H, KV, D, L = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.num_layers
@@ -71,6 +90,25 @@ def test_paged_decode_kernel_compiles(one_chip):
     )
     fn = jax.jit(lambda *a: paged_decode_attention(*a, interpret=False))
     assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_paged_decode_kernel_compiles_at_the_cells_sizes(one_chip, cell):
+    """The kernel alone at a cell's rows, pool and layer stack (phi4-mini:
+    160 rows, 3480 pages, 32 layers, GQA 24/8; granite-20b's stage: 256 rows,
+    16384 pages, 13 layers, MQA 48/1), under the name the benchmark's
+    ``paged_attn_roofline`` finds its events by."""
+    cfg = _cell_config(cell)
+    rows, pages, _ = CELLS[cell]
+    H, KV, D, L = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.num_layers
+    bf16 = jnp.bfloat16
+    pool = _sds((L, pages, PAGE, KV, D), bf16, one_chip)
+    compiled = jax.jit(lambda *a: paged_decode_attention(*a, interpret=False)).lower(
+        _sds((rows, H, D), bf16, one_chip), pool, pool,
+        _sds((rows, CELL_MAX_PAGES), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+    ).compile()
+    assert _kernel_names(compiled.as_text()) == {"paged_decode_attention"}
 
 
 def test_flash_attention_kernel_compiles(one_chip):
@@ -140,3 +178,31 @@ def test_engine_decode_step_fits_one_chip(one_chip, monkeypatch, use_kernels):
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, total
     assert ("tpu_custom_call" in compiled.as_text()) == use_kernels
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_decode_step_reads_the_pool_in_place(one_chip, monkeypatch, cell):
+    """The engine's donated paged decode step at a cell's size, with the
+    kernel: the pool is aliased from input to output, and the kernel reads
+    it through a bitcast, so no temporary holds a copy of either half of the
+    pool and the temporaries are no larger than they were."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    model = Model(_cell_config(cell), remat=False, use_kernels=True)
+    rows, pages, temp_before = CELLS[cell]
+
+    def on_chip(x):
+        return _sds(x.shape, x.dtype, one_chip)
+
+    params = jax.tree.map(on_chip, model.init(None, abstract=True)[0])
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init_paged_cache(rows, pages, PAGE, CELL_MAX_PAGES)
+    ))
+    compiled = decode_fn(model, "paged").lower(
+        params, cache,
+        _sds((rows, 1), jnp.int32, one_chip), _sds((rows,), jnp.int32, one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    half = cache["layers"]["pool_k"]
+    assert mem.alias_size_in_bytes >= 2 * half.size * half.dtype.itemsize
+    assert mem.temp_size_in_bytes < half.size * half.dtype.itemsize
+    assert mem.temp_size_in_bytes <= temp_before
