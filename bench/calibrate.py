@@ -50,10 +50,12 @@ def main() -> int:
     readers = [(md["name"], md["unit"], spec.reader(md["name"], False))
                for md in spec.metrics(bm, w["name"], False)]
     peak = peak_for(jax.devices()[0].device_kind)
+    arch = spec.arch(bm, w["config"])
     for seed in args.seeds:
-        out = run_cell(spec.config(bm, w["config"])["model"], spec.mix(w["traffic"]),
-                       spec.cell(w["name"]), seed, args.seconds, False,
-                       time.perf_counter(), readers, peak, control=args.control)
+        out = run_cell(arch, spec.config(bm, w["config"])["model"],
+                       spec.mix(w["traffic"]), spec.cell(w["name"]), seed,
+                       args.seconds, False, time.perf_counter(), readers, peak,
+                       control=args.control)
         line = {"workload": w["name"], "seed": seed, "correct": out["correct"],
                 "checks": {k: v["value"] for k, v in out["checks"].items()},
                 "metrics": {k: v["value"] for k, v in out["metrics"].items()}}

@@ -76,6 +76,7 @@ class Run:
     """What the metric readers read."""
 
     m: Mapping[str, Any]  # the configuration's model block
+    arch: Any  # its architecture module, bench/arch/<arch>.py
     peak: Any  # bench.peaks.Peak of the device
     setup_s: float
     w0: float
@@ -87,13 +88,14 @@ class Run:
     trace: Optional[TraceView]
 
 
-def build_engine(m: Mapping, sizes: Mapping, seed: int) -> Engine:
-    """The cell's engine.  ``sizes`` gives ``batch`` and ``max_len``, and
+def build_engine(arch: Any, m: Mapping, sizes: Mapping, seed: int) -> Engine:
+    """The cell's engine, on the weights that architecture module ``arch``
+    draws for ``m``.  ``sizes`` gives ``batch`` and ``max_len``, and
     ``kv_pool_bytes`` where the pool is sized from the chip's memory rather
     than as ``batch`` rows of ``max_len``."""
     model = Model(ModelConfig(**m), remat=False, use_kernels=True)
     abstract, _ = model.init(None, abstract=True)
-    params = wmod.program_params(abstract, wmod.make_weights(m, seed))
+    params = wmod.program_params(abstract, wmod.make_weights(arch, m, seed))
     return Engine(model, params, batch=sizes["batch"], max_len=sizes["max_len"],
                   kv_backend="paged", page_size=PAGE_SIZE,
                   hbm_budget_bytes=sizes.get("kv_pool_bytes"))
@@ -172,12 +174,13 @@ def sample(tracked: Sequence[Tracked], w0: float, w1: float, seed: int,
     return picked
 
 
-def check(m: Mapping, seed: int, picked: Sequence[Tracked], failed: int,
+def check(arch: Any, m: Mapping, seed: int, picked: Sequence[Tracked], failed: int,
           limits: Mapping[str, float], control: bool = False) -> Dict[str, Dict]:
-    """Compare the served tokens with the plain reference.  Returns each
-    number compared with its limit.  ``control`` puts the fp8 control in
-    the program's place over the same prompts and tokens: its widest gap is
-    held to the served tokens' limit, so a control run is not correct."""
+    """Compare the served tokens with the plain reference of architecture
+    module ``arch``.  Returns each number compared with its limit.
+    ``control`` puts the fp8 control in the program's place over the same
+    prompts and tokens: its widest gap is held to the served tokens' limit,
+    so a control run is not correct."""
     V = m["vocab_size"]
     bad = sum(
         int(len(t.req.out_tokens) != t.req.max_new_tokens)
@@ -187,8 +190,8 @@ def check(m: Mapping, seed: int, picked: Sequence[Tracked], failed: int,
     out: Dict[str, Dict] = {}
     gap = None
     if picked and not bad:
-        w = wmod.make_weights(m, seed)
-        gaps = Reference(m).token_gaps(
+        w = wmod.make_weights(arch, m, seed)
+        gaps = Reference(arch, m).token_gaps(
             w, [(t.req.prompt, t.req.out_tokens) for t in picked], control)
         del w
         gap = widest(gaps, "served")
@@ -222,16 +225,18 @@ def reduce_trace(trace_dir: str, iters: List[Iteration], window_s: float) -> Tra
 
 
 def run_cell(
-    m: Mapping, mix: Mapping, cellp: Mapping, seed: int, seconds: float,
+    arch: Any, m: Mapping, mix: Mapping, cellp: Mapping, seed: int, seconds: float,
     trace: bool, t_start: float, readers: Sequence, peak: Any,
     control: bool = False, trace_seconds: float = TRACE_SECONDS,
     log: Callable[[str], None] = lambda s: print(s, file=sys.stderr),
 ) -> Dict[str, Any]:
-    """One run of a cell; returns the result's fields other than ``device``.
-    ``readers`` is ``[(name, unit, read)]`` of the metrics to report."""
+    """One run of a cell of configuration ``m``, of architecture module
+    ``arch`` (:func:`bench.spec.arch`); returns the result's fields other
+    than ``device``.  ``readers`` is ``[(name, unit, read)]`` of the metrics
+    to report."""
     clock = time.perf_counter
     counter = CompileCounter()
-    engine = build_engine(m, cellp["engine"], seed)
+    engine = build_engine(arch, m, cellp["engine"], seed)
     buckets = prefill_buckets(engine, mix)
     engine.compile(buckets)
     log(f"built and compiled {len(buckets)} prefill buckets at {clock() - t_start:.1f} s")
@@ -254,7 +259,7 @@ def run_cell(
             shutil.rmtree(trace_dir, ignore_errors=True)
     mem = peak_memory()
     host_iters = drv.iters[first:tr_i] if tr_i is not None else drv.iters[first:]
-    run = Run(m, peak, setup_s, w0, w1, host_iters, drv.tracked, counter.count,
+    run = Run(m, arch, peak, setup_s, w0, w1, host_iters, drv.tracked, counter.count,
               engine.pool.num_pages, view)
     metrics = {}
     for name, unit, read in readers:
@@ -272,7 +277,7 @@ def run_cell(
     drv.engine = engine = None
     gc.collect()
     t_ref = clock()
-    checks = check(m, seed, picked, failed, chk["limits"], control)
+    checks = check(arch, m, seed, picked, failed, chk["limits"], control)
     log(f"reference over {len(picked)} requests took {clock() - t_ref:.1f} s")
     out = {
         "correct": passed(checks),

@@ -1,22 +1,23 @@
 """Kernel ``kernels/paged_attention.py``: share of its roofline over the traced
 decode steps, in percent.  The least time is, per call (one layer of one
 step), the larger of its FLOPs over peak and its bytes over bandwidth, with
-the bytes those of the live tokens' K/V and of q and out
-(``bench/flops.py``); it is divided by the summed device time of the
-kernel's events in the trace, named ``paged_decode_attention``."""
+the bytes those of the live tokens' K/V and of q and out (the architecture
+module's cost of the kernel, ``KERNEL_COSTS``); it is divided by the summed
+device time of the kernel's events in the trace, named
+``paged_decode_attention``."""
 
-from flops import paged_attn_cost
 from trace_reduce import kernel_events, roofline_share
 
-NAMES = ("paged_decode_attention",)
+NAME = "paged_decode_attention"
 
 
 def read(run):
     if run.trace is None:
         return None
-    events = kernel_events(run.trace.device, NAMES)
+    events = kernel_events(run.trace.device, (NAME,))
+    cost = run.arch.KERNEL_COSTS[NAME]
     layers = run.m["num_layers"]
-    calls = [paged_attn_cost(run.m, i.ctx_lens)
+    calls = [cost(run.m, i.ctx_lens)
              for i in run.trace.iters if i.kind == "step"] * layers
     share = roofline_share(calls, sum(e.dur for e in events),
                            run.peak.bf16_flops, run.peak.hbm_bytes_per_s)

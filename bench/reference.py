@@ -1,13 +1,15 @@
-"""The plain reference: a float32 ``jax.numpy`` forward of the dense decoder.
+"""The plain reference: a float32 ``jax.numpy`` forward of the decoder.
 
-It imports nothing of the program.  Its block is the one the configuration
-file states (``bench/configs/<name>.json``): RMSNorm, rotary embedding over
-the whole head (the two halves rotated against each other), grouped-query
-attention (one KV head is MQA), a SwiGLU or a tanh-GELU MLP, and a head that
-is tied to the embedding or not.  Every matrix product runs at
-``Precision.HIGHEST``.  It runs layer by layer, each layer over every sequence,
-with attention in blocks of :data:`Q_BLOCK` query rows, so it fits beside
-the weights at the benchmark's largest contexts.
+It imports nothing of the program.  What is the same for every block is
+here: the embedding, the layer loop, the final RMSNorm and the head (tied to
+the embedding or not), and the check.  The decoder layer is the one the
+configuration's architecture module states (``layer_weights`` and ``layer``
+in ``bench/arch/<arch>.py``), which builds it from the helpers here.  Every
+matrix product runs at ``Precision.HIGHEST``.  It runs layer by layer, each
+layer over every sequence, each sequence padded to whole blocks of
+:data:`Q_BLOCK` rows for a layer's attention to take a block of query rows
+at a time, so it fits beside the weights at the benchmark's largest
+contexts.
 
 The check (:func:`token_gaps`) runs each request's prompt followed by its
 served tokens, and reads, at the position of every served token, how far the
@@ -21,7 +23,7 @@ would be tempted by, which the check has to refuse.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,66 +62,18 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def gelu_tanh(x):
-    return 0.5 * x * (1.0 + jnp.tanh(np.sqrt(2.0 / np.pi) * (x + 0.044715 * x ** 3)))
-
-
 class Reference:
-    """The forward of configuration ``m`` on the benchmark's weights
-    (:func:`bench.weights.make_weights`), in float32 or, with ``low``, in
-    fp8."""
+    """The forward of configuration ``m``, of architecture module ``arch``,
+    on the benchmark's weights (:func:`bench.weights.make_weights`), in
+    float32 or, with ``low``, in fp8."""
 
-    def __init__(self, m: Mapping):
+    def __init__(self, arch: Any, m: Mapping):
+        self.arch = arch
         self.m = dict(m)
-        self._layer = {lo: jax.jit(functools.partial(self._layer_fn, low=lo))
+        self._layer = {lo: jax.jit(functools.partial(arch.layer, self.m, low=lo))
                        for lo in (False, True)}
         self._embed = jax.jit(lambda e, t: e[t].astype(jnp.float32))
         self._head = jax.jit(self._head_fn)
-
-    # -- one decoder layer --------------------------------------------------
-    def _layer_fn(self, x, w: Dict[str, jax.Array], i, *, low: bool):
-        m = self.m
-        S = x.shape[0]
-        h, kv, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-        g = h // kv
-
-        def wt(name):
-            a = jax.lax.dynamic_index_in_dim(w[name], i, 0, keepdims=False)
-            return a.astype(jnp.float32)
-
-        def lin(a, name):
-            b = wt(name)
-            if low:
-                a, b = fp8(a, -1), fp8(b, 0)
-            return _mm("sd,de->se", a, b)
-
-        pos = jnp.arange(S)
-        a = rmsnorm(x, wt("ln1"), m["norm_eps"])
-        q = rope(lin(a, "wq").reshape(S, h, hd), pos, m["rope_theta"])
-        k = rope(lin(a, "wk").reshape(S, kv, hd), pos, m["rope_theta"])
-        v = lin(a, "wv").reshape(S, kv, hd)
-        if low:
-            q, k, v = fp8(q, -1), fp8(k, -1), fp8(v, -1)
-        nb = S // Q_BLOCK
-        qb = q.reshape(nb, Q_BLOCK, kv, g, hd)
-        scale = 1.0 / np.sqrt(hd)
-
-        def block(args):
-            qi, b = args
-            s = _mm("qkgh,skh->kgqs", qi, k) * scale
-            qpos = b * Q_BLOCK + jnp.arange(Q_BLOCK)
-            s = jnp.where(pos[None, :] <= qpos[:, None], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            return _mm("kgqs,skh->qkgh", p, v)
-
-        o = jax.lax.map(block, (qb, jnp.arange(nb))).reshape(S, h * hd)
-        x = x + lin(o, "wo")
-        a = rmsnorm(x, wt("ln2"), m["norm_eps"])
-        if m["mlp_gated"]:
-            u = jax.nn.silu(lin(a, "w_gate")) * lin(a, "w_up")
-        else:
-            u = gelu_tanh(lin(a, "w_up"))
-        return x + lin(u, "w_down")
 
     # -- output projection over the real vocabulary -------------------------
     def _head_fn(self, h_ref, h_low, tokens, final_norm, head):
@@ -149,8 +103,6 @@ class Reference:
         """The last layer's output for each token sequence, padded up to a
         whole :data:`Q_BLOCK`: ``[[float32], ...]``, or with ``control``
         ``[[float32, fp8], ...]``.  Layer by layer, over every sequence."""
-        layers = {k[len("layers."):]: v for k, v in weights.items()
-                  if k.startswith("layers.")}
         xs = []
         for toks in seqs:
             S = -(-len(toks) // Q_BLOCK) * Q_BLOCK
@@ -160,11 +112,12 @@ class Reference:
             xs.append([x, x] if control else [x])
         with jax.default_matmul_precision("highest"):
             for i in range(self.m["num_layers"]):
-                li = jnp.int32(i)
+                w, j = self.arch.layer_weights(self.m, weights, i)
+                j = jnp.int32(j)
                 for pair in xs:
-                    pair[0] = self._layer[False](pair[0], layers, li)
+                    pair[0] = self._layer[False](pair[0], w, j)
                     if control:
-                        pair[1] = self._layer[True](pair[1], layers, li)
+                        pair[1] = self._layer[True](pair[1], w, j)
         return xs
 
     def logits(self, weights: Mapping[str, jax.Array], tokens: np.ndarray) -> np.ndarray:

@@ -3,13 +3,14 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 from the root of a checkout, on a machine with the TPU chips the cell asks
-for.  The cell, its configuration, its traffic mix and its metrics are found
-by name from ``BENCHMARK.json`` (see ``bench/spec.py``).  The last line of
-standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
-per-layer ones), ``device``, with ``--trace 1`` a ``breakdown``, and last
-``checks``: each number compared with the reference beside its limit, which
-are also the last lines of standard error.
+for.  The cell, its configuration and that configuration's architecture,
+its traffic mix and its metrics are found by name from ``BENCHMARK.json``
+(see ``bench/spec.py``).  The last line of standard output is one JSON
+object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
+end-to-end metrics, or with ``--trace 1`` its per-layer ones), ``device``,
+with ``--trace 1`` a ``breakdown``, and last ``checks``: each number compared
+with the reference beside its limit, which are also the last lines of
+standard error.
 
 It exits with code 2 and prints no result when JAX finds no TPU, fewer chips
 than the cell asks for, or a chip with no entry in ``bench/peaks.py``.
@@ -46,6 +47,7 @@ def main(argv=None) -> int:
     bm = spec.benchmark()
     w = spec.workload(bm, args.workload)
     model = spec.config(bm, w["config"])["model"]
+    arch = spec.arch(bm, w["config"])
     mix = spec.mix(w["traffic"])
     cellp = spec.cell(w["name"])
     per_layer = bool(args.trace)
@@ -74,7 +76,7 @@ def main(argv=None) -> int:
 
     from cell import run_cell
 
-    out = run_cell(model, mix, cellp, args.seed, args.seconds, per_layer,
+    out = run_cell(arch, model, mix, cellp, args.seed, args.seconds, per_layer,
                    T_START, readers, peak)
     used = devices[: w["chips"]]
     device = {

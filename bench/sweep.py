@@ -45,12 +45,13 @@ def main() -> int:
     bm = spec.benchmark()
     w = spec.workload(bm, args.workload)
     m = spec.config(bm, w["config"])["model"]
+    arch = spec.arch(bm, w["config"])
     base = spec.mix(w["traffic"])
     cellp = spec.cell(w["name"])
     readers = [(md["name"], spec.reader(md["name"], False))
                for md in spec.metrics(bm, w["name"], False)]
     peak = peak_for(jax.devices()[0].device_kind)
-    engine = build_engine(m, cellp["engine"], args.seed)
+    engine = build_engine(arch, m, cellp["engine"], args.seed)
     engine.compile(prefill_buckets(engine, base))
     counter = CompileCounter()
     for rate in args.rates:
@@ -63,7 +64,7 @@ def main() -> int:
         due = sum(1 for t in drv.tracked if w0 <= t.due <= w1)
         admitted = sum(1 for t in drv.tracked
                        if t.admit_start is not None and w0 <= t.admit_start <= w1)
-        run = Run(m, peak, 0.0, w0, w1, drv.iters[first:], drv.tracked,
+        run = Run(m, arch, peak, 0.0, w0, w1, drv.iters[first:], drv.tracked,
                   counter.count, engine.pool.num_pages, None)
         line = {"rate": rate, "queue_start": q0, "queue_end": q1, "due": due,
                 "admitted": admitted, "window_s": w1 - w0,

@@ -1,6 +1,11 @@
 """Tests of the benchmark's own code, on the CPU at small sizes.
 
 Run from the repository root:  python -m pytest -q bench/tests
+
+The small configurations (``small.py``) run the dense block, whose module
+``small.DENSE`` is, and the tests hand it to the harness as ``bench/run.py``
+hands it the module a configuration names.  ``test_arch.py`` pins that
+module's numbers and runs a cell under a module of its own.
 """
 
 import os

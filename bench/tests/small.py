@@ -1,6 +1,12 @@
 """Small configurations, mixes and cells for the CPU tests: the same keys as
 the files under ``bench/configs``, ``bench/traffic`` and ``bench/cells``."""
 
+import spec
+
+# The small configurations are of the dense block: the architecture module
+# that a configuration file naming no "arch", as phi4-mini's, is run with.
+DENSE = spec.arch(spec.benchmark(), "phi4-mini-3.8b")
+
 GQA = {
     "name": "small-gqa", "arch_type": "dense", "num_layers": 2, "d_model": 64,
     "num_heads": 4, "num_kv_heads": 2, "head_dim": 16, "d_ff": 128,

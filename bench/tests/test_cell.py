@@ -13,7 +13,7 @@ import pytest
 import cell
 import spec
 from peaks import PEAKS
-from small import BACKLOG, CELL, GQA, MQA, POISSON, TRACE_SECONDS
+from small import BACKLOG, CELL, DENSE, GQA, MQA, POISSON, TRACE_SECONDS
 
 BM = spec.benchmark()
 PEAK = PEAKS["TPU v5 lite"]
@@ -43,9 +43,10 @@ def _readers(workload, per_layer):
 
 def _run(m=GQA, mix=BACKLOG, seed=3, trace=False, workload=BACKLOG_CELL,
          control=False, seconds=2.0):
-    return cell.run_cell(m, mix, CELL, seed, seconds, trace, time.perf_counter(),
-                         _readers(workload, trace), PEAK, control=control,
-                         trace_seconds=TRACE_SECONDS, log=lambda s: None)
+    return cell.run_cell(DENSE, m, mix, CELL, seed, seconds, trace,
+                         time.perf_counter(), _readers(workload, trace), PEAK,
+                         control=control, trace_seconds=TRACE_SECONDS,
+                         log=lambda s: None)
 
 
 @pytest.mark.parametrize("m,mix,workload", [

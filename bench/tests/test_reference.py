@@ -10,7 +10,7 @@ import pytest
 
 import weights
 from reference import Reference, fp8
-from small import GQA, MQA
+from small import DENSE, GQA, MQA
 
 from repro.models import Model
 from repro.models.config import ModelConfig
@@ -28,9 +28,9 @@ def test_float32_program_matches_reference(m):
     # Tolerance 1e-4: both sides compute in float32 on the same weights and
     # differ only in the order of summation; logits are of unit scale.
     m = dict(m, dtype="float32")
-    w = weights.make_weights(m, 5)
+    w = weights.make_weights(DENSE, m, 5)
     tokens = np.random.default_rng(0).integers(1, m["vocab_size"], 40)
-    ref = Reference(m).logits(w, tokens)
+    ref = Reference(DENSE, m).logits(w, tokens)
     got = _program_logits(m, w, tokens)
     assert ref.shape == got.shape
     assert np.max(np.abs(ref - got)) < 1e-4
@@ -42,17 +42,17 @@ def test_bfloat16_program_is_near_reference(m):
     # (8 bits of mantissa, relative rounding 4e-3 per op) through two layers
     # and the head; logits are of unit scale.  An error in the block (a
     # missing norm, a wrong rotation) moves logits by about their scale.
-    w = weights.make_weights(m, 6)
+    w = weights.make_weights(DENSE, m, 6)
     tokens = np.random.default_rng(1).integers(1, m["vocab_size"], 40)
-    ref = Reference(m).logits(w, tokens)
+    ref = Reference(DENSE, m).logits(w, tokens)
     got = _program_logits(m, w, tokens)
     assert np.std(ref) > 0.1  # a tied head at width 64: 0.02 * sqrt(64)
     assert np.max(np.abs(ref - got)) < 0.1
 
 
 def test_token_gaps_are_zero_for_the_reference_argmax():
-    w = weights.make_weights(GQA, 7)
-    ref = Reference(GQA)
+    w = weights.make_weights(DENSE, GQA, 7)
+    ref = Reference(DENSE, GQA)
     prompt = np.arange(1, 21, dtype=np.int32)
     served = []
     for _ in range(5):

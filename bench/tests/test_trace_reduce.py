@@ -1,9 +1,10 @@
-"""The trace reduction and the FLOP and byte counts, on synthetic traces."""
+"""The trace reduction, on synthetic traces, and the dense block's FLOP and
+byte counts."""
 
 import pytest
 
-import flops
 import trace_reduce as tr
+from small import DENSE as dense
 from trace_reduce import Event
 
 from repro.serving.paged_cache import PagePool
@@ -80,34 +81,34 @@ def test_paged_bytes_follow_live_lengths_not_page_table_or_grid():
     pt_a, la = _lengths_from(128, 32, lengths, range(128))
     pt_b, lb = _lengths_from(512, 64, lengths, reversed(range(512)))
     assert pt_a.shape != pt_b.shape  # another grid (max pages) and table
-    assert flops.paged_attn_cost(M, la) == flops.paged_attn_cost(M, lb)
-    f0, b0 = flops.paged_attn_cost(M, la)
-    f1, b1 = flops.paged_attn_cost(M, [n + 1 for n in la])
+    assert dense.paged_attn_cost(M, la) == dense.paged_attn_cost(M, lb)
+    f0, b0 = dense.paged_attn_cost(M, la)
+    f1, b1 = dense.paged_attn_cost(M, [n + 1 for n in la])
     assert b1 - b0 == pytest.approx(len(la) * 2 * M["num_kv_heads"] * M["head_dim"] * 2)
     assert f1 > f0
 
 
 def test_paged_cost_counts_kv_of_live_tokens_and_q_out_of_live_rows():
-    f, b = flops.paged_attn_cost(M, [10, 20])
+    f, b = dense.paged_attn_cost(M, [10, 20])
     assert f == 4 * 4 * 16 * 30
     assert b == 30 * 2 * 2 * 16 * 2 + 2 * 2 * 4 * 16 * 2
 
 
 def test_flash_cost_is_the_causal_half():
-    f, b = flops.flash_attn_cost(M, 4)
+    f, b = dense.flash_attn_cost(M, 4)
     assert f == 4 * 4 * 16 * (4 * 5 // 2)
     assert b == 4 * (2 * 4 + 2 * 2) * 16 * 2
 
 
 def test_decode_and_prefill_flops_count_real_work_only():
-    per_row = 2 * (2 * flops.layer_matmul_params(M) + flops.head_params(M))
-    assert flops.decode_step_flops(M, []) == 0
-    assert flops.decode_step_flops(M, [5]) == per_row + 4 * 4 * 16 * 2 * 5
-    assert flops.prefill_flops(M, 1) == (2 * 2 * flops.layer_matmul_params(M)
-                                         + 4 * 4 * 16 * 2 + 2 * flops.head_params(M))
+    per_row = 2 * (2 * dense.layer_matmul_params(M) + dense.head_params(M))
+    assert dense.decode_step_flops(M, []) == 0
+    assert dense.decode_step_flops(M, [5]) == per_row + 4 * 4 * 16 * 2 * 5
+    assert dense.prefill_flops(M, 1) == (2 * 2 * dense.layer_matmul_params(M)
+                                         + 4 * 4 * 16 * 2 + 2 * dense.head_params(M))
 
 
 def test_layer_params_gated_and_plain_mlp():
-    assert flops.layer_matmul_params(M) == 64 * 16 * (4 + 4) + 4 * 16 * 64 + 3 * 64 * 128
+    assert dense.layer_matmul_params(M) == 64 * 16 * (4 + 4) + 4 * 16 * 64 + 3 * 64 * 128
     plain = dict(M, mlp_gated=False)
-    assert flops.layer_matmul_params(plain) == flops.layer_matmul_params(M) - 64 * 128
+    assert dense.layer_matmul_params(plain) == dense.layer_matmul_params(M) - 64 * 128

@@ -8,9 +8,10 @@ large leaf one row slab at a time, so a float32 draw never needs more than
 one slice beside the weights.  The plain reference makes the same weights
 again with the same call; it takes nothing the program made.
 
-The weights are held under the benchmark's own names (:func:`shapes`);
-:func:`program_params` lays them into the tree the program's ``Model``
-expects, matching leaves by name, without copying.
+The weights are held under the benchmark's own names, as the
+configuration's architecture module gives them (``shapes`` in
+``bench/arch/<arch>.py``); :func:`program_params` lays them into the tree
+the program's ``Model`` expects, matching leaves by name, without copying.
 """
 
 from __future__ import annotations
@@ -20,7 +21,6 @@ from typing import Any, Dict, Mapping, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 EMBED_STD = 0.02
 _SLABS = 16  # row slabs of a large unstacked leaf
@@ -29,35 +29,6 @@ _SLABS = 16  # row slabs of a large unstacked leaf
 def padded_vocab(m: Mapping) -> int:
     p = m["vocab_pad"]
     return -(-m["vocab_size"] // p) * p
-
-
-def shapes(m: Mapping) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
-    """``{name: (shape, init)}`` with ``init`` a std or ``"ones"``.  Leaves
-    under ``layers.`` carry a leading layer axis."""
-    d, hd, ff = m["d_model"], m["head_dim"], m["d_ff"]
-    h, kv, L = m["num_heads"], m["num_kv_heads"], m["num_layers"]
-    vp = padded_vocab(m)
-    out: Dict[str, Tuple[Tuple[int, ...], Any]] = {
-        "embed": ((vp, d), EMBED_STD),
-        "final_norm": ((d,), "ones"),
-    }
-    if not m["tie_embeddings"]:
-        out["head"] = ((d, vp), 1.0 / np.sqrt(d))
-    layer = {
-        "ln1": ((d,), "ones"),
-        "wq": ((d, h * hd), 1.0 / np.sqrt(d)),
-        "wk": ((d, kv * hd), 1.0 / np.sqrt(d)),
-        "wv": ((d, kv * hd), 1.0 / np.sqrt(d)),
-        "wo": ((h * hd, d), 1.0 / np.sqrt(h * hd)),
-        "ln2": ((d,), "ones"),
-        "w_up": ((d, ff), 1.0 / np.sqrt(d)),
-        "w_down": ((ff, d), 1.0 / np.sqrt(ff)),
-    }
-    if m["mlp_gated"]:
-        layer["w_gate"] = ((d, ff), 1.0 / np.sqrt(d))
-    for name, (shape, init) in layer.items():
-        out[f"layers.{name}"] = ((L,) + shape, init)
-    return out
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -98,10 +69,10 @@ def _maker(spec: Tuple[Tuple[str, Tuple[int, ...], Any], ...], dtype_name: str):
     return jax.jit(make)
 
 
-def make_weights(m: Mapping, seed: int) -> Dict[str, jax.Array]:
-    """All weights of configuration ``m`` for ``seed``, on the default
-    device, in one jitted call."""
-    spec = tuple((name, shape, init) for name, (shape, init) in sorted(shapes(m).items()))
+def make_weights(arch: Any, m: Mapping, seed: int) -> Dict[str, jax.Array]:
+    """All weights of configuration ``m``, of architecture module ``arch``,
+    for ``seed``, on the default device, in one jitted call."""
+    spec = tuple((name, shape, init) for name, (shape, init) in sorted(arch.shapes(m).items()))
     return _maker(spec, m["dtype"])(seed_key(seed))
 
 
